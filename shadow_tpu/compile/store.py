@@ -91,17 +91,17 @@ def _compile_outside_xla_cache(lowered):
 
 
 def default_root() -> pathlib.Path:
-    """Store root: $SHADOW_AOT_DIR, else `aot/` inside the claimed
-    compile-cache dir — claim/redirect included, so foreign-featured
-    hosts get their own namespace exactly like the JAX cache."""
+    """Store root: $SHADOW_AOT_DIR, else `aot/` inside the shared
+    compile-cache dir (utils/compcache.cache_dir: exactly
+    $JAX_COMPILATION_CACHE_DIR when set, else the claimed repo-local
+    dir, so foreign-featured hosts get their own namespace exactly
+    like the JAX cache)."""
     env = os.environ.get("SHADOW_AOT_DIR")
     if env:
         return pathlib.Path(env)
-    from shadow_tpu.utils.compcache import (_claim_or_redirect,
-                                            machine_fingerprint)
-    cache = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
-    return _claim_or_redirect(cache, machine_fingerprint(),
-                              log=lambda m: None) / "aot"
+    from shadow_tpu.utils.compcache import cache_dir
+
+    return cache_dir(log=lambda m: None) / "aot"
 
 
 class ProgramStore:
@@ -150,15 +150,25 @@ class ProgramStore:
     def load(self, key: str, avals: str):
         """Deserialize the stored executable for `key`, or None on any
         mismatch/corruption (the caller falls back to compiling)."""
+        import jax
         from jax.experimental import serialize_executable
 
-        if self._loadable(key, avals) is None:
+        meta = self._loadable(key, avals)
+        if meta is None:
+            return None
+        # Left unset, the loader places the program on EVERY local
+        # device: a one-device program then fails at call time on a
+        # host with more devices. Load it on the devices it was
+        # compiled for.
+        n = meta.get("devices")
+        if not isinstance(n, int) or not 0 < n <= len(jax.devices()):
             return None
         try:
             payload, in_tree, out_tree = pickle.loads(
                 self.bin_path(key).read_bytes())
             compiled = serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree,
+                execution_devices=jax.devices()[:n])
         except Exception:
             return None
         # LRU touch for gc(): served entries are the ones worth keeping.
@@ -195,6 +205,8 @@ class ProgramStore:
                 "code": buckets.code_version(),
                 "jax": jax.__version__,
                 "machine": machine_fingerprint(),
+                "devices": len(
+                    compiled.runtime_executable().local_devices()),
                 "nbytes": len(blob),
             }
             sidecar.update(meta or {})

@@ -365,16 +365,17 @@ def compact_rows(q: EventQueue) -> EventQueue:
     )
 
 
-def segment_ranks(sorted_keys: jax.Array) -> jax.Array:
-    """[n] rank of each element within its run of equal keys (keys must
-    already be sorted)."""
+def segment_ranks(sorted_keys: jax.Array, num_keys: int) -> jax.Array:
+    """[n] i32 rank of each element within its run of equal keys (keys
+    must already be sorted, each in [0, num_keys]). Counted per key
+    and offset by the exclusive prefix sum over the num_keys + 1
+    keys: a running max over all n elements (the obvious form) took
+    the TPU compiler 124 s at n=491,520 (v5e, jax 0.9), this 1.6 s."""
     n = sorted_keys.shape[0]
-    pos = jnp.arange(n)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_keys[1:] != sorted_keys[:-1]]
-    )
-    seg_start = jax.lax.cummax(jnp.where(is_start, pos, 0))
-    return pos - seg_start
+    cnt = jnp.zeros((num_keys + 1,), I32).at[sorted_keys].add(
+        1, indices_are_sorted=True)
+    start = jnp.cumsum(cnt, dtype=I32) - cnt
+    return jnp.arange(n, dtype=I32) - start[sorted_keys]
 
 
 # Group width for insert_flat's sort-free "count-route": cross-group
@@ -393,13 +394,12 @@ SLOT_CUBE_BUDGET = 1_000_000_000
 
 def _insert_impl(n: int, H: int) -> str:
     if jax.default_backend() == "cpu":
-        # CPU gathers/sorts are cheap; the packed-plane co-sort and
-        # padded scatter are pure waste there
+        # CPU gathers/sorts are cheap; the select sweep and padded
+        # scatter are pure waste there
         return "sort"
-    # multi-operand co-sort + lexicographically sorted scatter: no
-    # count matrix, no cube, no per-entry permutation gathers — and
-    # no scale ceiling (the count matrix at 100k hosts would be
-    # ~30 GB; sort2 is O(n log n) compare-exchange on packed planes)
+    # key sort + select sweep (Pallas mailbox) or sorted scatter: no
+    # count matrix, no cube — and no scale ceiling (the count matrix
+    # at 100k hosts would be ~30 GB)
     return "sort2"
 
 
@@ -468,11 +468,10 @@ def _queue_unpacked(q: EventQueue, packed_q, overflow_add,
 
 
 def _insert_sorted_scatter(q: EventQueue, rowc, packed, n, H, K):
-    """The "sort2" insert mechanism: co-sort the packed planes by
-    destination row with one multi-operand lax.sort (the permutation
-    happens inside the vectorized sort network — no per-entry plane
-    gathers, which is what made the classic argsort+shuffle form slow
-    on TPU), then apply the sorted stream with one of two writers:
+    """The "sort2" insert mechanism: sort the entries by destination
+    row (a two-operand stable lax.sort of the row key and the entry
+    index), permute the packed planes with ONE [n, P] row gather, then
+    apply the sorted stream with one of two writers:
 
     - select sweep (common case, every destination row receives at
       most INSERT_SWEEP entries): per-row arrival counts come from one
@@ -491,10 +490,14 @@ def _insert_sorted_scatter(q: EventQueue, rowc, packed, n, H, K):
     way: the stable sort preserves caller order within each row, so
     ranks and chosen free slots agree entry-for-entry."""
     P = packed.shape[1]
-    cols = tuple(packed[:, j] for j in range(P))
-    srt = jax.lax.sort((rowc,) + cols, num_keys=1, is_stable=True)
-    row_o = srt[0]
-    packed_o = jnp.stack(srt[1:], axis=1)                  # [n, P]
+    # Not a co-sort of all P planes: the TPU compiler's time for a
+    # stable multi-operand sort grows steeply with operands x length
+    # (v5e, jax 0.9: 23 operands took 7.6 s at n=4,096 and 152 s at
+    # n=16,384, where 2 operands took 5.4 s; the 10,240-host PHOLD
+    # route has n=491,520). Same stable order, so the same stream.
+    row_o, perm = jax.lax.sort((rowc, jnp.arange(n, dtype=I32)),
+                               num_keys=1, is_stable=True)
+    packed_o = packed[perm]                                # [n, P]
     valid_o = row_o < H
 
     # per-destination-row arrival counts (invalid entries fall in the
@@ -549,7 +552,7 @@ def _insert_sorted_scatter(q: EventQueue, rowc, packed, n, H, K):
         return acc, ofl
 
     def _sorted_scatter(_):
-        rank_o = segment_ranks(row_o)
+        rank_o = segment_ranks(row_o, H)
         slot_map = _free_slot_of_rank(q, "sort")           # [H, K]
         # Keep the clipped index sequence genuinely sorted for the
         # hint: invalid entries (row H, clipped to H-1) restart
@@ -602,9 +605,10 @@ def insert_flat(
     place). Three bit-identical mechanisms, chosen per backend by
     _insert_impl:
 
-    - "sort2" (accelerators, the default): one multi-operand lax.sort
-      co-sorting the packed planes by destination row, then a single
-      lexicographically sorted scatter (_insert_sorted_scatter).
+    - "sort2" (accelerators, the default): one stable sort of the
+      destination rows, the packed planes permuted by one row gather,
+      then the select-sweep or sorted-scatter writer
+      (_insert_sorted_scatter).
     - "sort" (CPU): stable argsort by row + segment ranks, the
       classic shuffle — cheap where gathers are cheap.
     - "count" (kept for measurement, no longer auto-selected):
@@ -654,7 +658,7 @@ def insert_flat(
         row_o = rowc[order]
         packed_o = packed[order]
         valid_o = row_o < H
-        rank_o = segment_ranks(row_o)
+        rank_o = segment_ranks(row_o, H)
 
     slot_map = _free_slot_of_rank(q, impl)                 # [H,K]
     cand = slot_map[

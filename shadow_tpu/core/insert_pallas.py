@@ -27,14 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover - pallas ships with jax
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _BLOCK_HOSTS = 256
 _DMA_DEPTH = 16
@@ -59,7 +53,7 @@ def mailbox_available(num_hosts: int) -> bool:
 
     if os.environ.get("SHADOW_NO_PALLAS") == "1":
         return False
-    return HAVE_PALLAS and num_hosts <= _MAX_SMEM_START_ROWS
+    return num_hosts <= _MAX_SMEM_START_ROWS
 
 
 def _kernel(Wn: int, B: int, D: int, start_ref, stream_ref, out_ref,
@@ -120,7 +114,7 @@ def _call(stream, start, Wn, H, P, B, D):
         grid=(H // B,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
             (B, Wn, P), lambda b: (b, 0, 0), memory_space=pltpu.VMEM),

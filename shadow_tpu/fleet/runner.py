@@ -54,6 +54,40 @@ EXIT_PREEMPTED = 5
 EXIT_STALLED = 6
 
 
+def worker_platforms() -> str | None:
+    """The JAX platforms this process asked for (jax.config, which
+    JAX_PLATFORMS feeds), handed to every worker. Reading the config
+    starts no backend: this process must not hold a chip its workers
+    need."""
+    import jax
+
+    return jax.config.jax_platforms or None
+
+
+def worker_chips(platforms: str | None) -> int:
+    """TPU chips one worker on `platforms` takes: every chip on the
+    host, since a JAX process takes all of them, and none when the
+    workers are put on the CPU (platforms starting with "cpu").
+    Counted on the PCI bus, as JAX does before it starts a backend."""
+    if (platforms or "").split(",")[0] == "cpu":
+        return 0
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+
+    return num_available_tpu_chips_and_device_id()[0]
+
+
+def check_chip_workers(workers: int, platforms: str | None) -> None:
+    """Refuse a pool that would fight over the accelerator: a host
+    with chips has room for one chip-holding worker."""
+    chips = worker_chips(platforms) if workers > 1 else 0
+    if chips:
+        raise ValueError(
+            f"fleet: {workers} workers would each claim this host's "
+            f"{chips} TPU chip(s), and a JAX process takes all of "
+            "them: run one worker, or put the workers on the CPU "
+            "(JAX_PLATFORMS=cpu)")
+
+
 def _is_fatal(result: dict) -> bool:
     """Deterministic spec/build-level errors re-raise identically on
     retry; burn no attempts on them."""
@@ -68,6 +102,8 @@ class FleetRunner:
                  salvage: bool = True, drain_timeout_s: float = 60.0,
                  respawn_budget: int = 4, on_event=None, log=None,
                  now=time.time):
+        self._platforms = worker_platforms()
+        check_chip_workers(max(1, workers), self._platforms)
         os.makedirs(fleet_dir, exist_ok=True)
         self.fleet_dir = fleet_dir
         self.policy = policy
@@ -114,7 +150,8 @@ class FleetRunner:
         self._next_wid += 1
         parent, child = self._ctx.Pipe()
         proc = self._ctx.Process(
-            target=_entry, args=(wid, self.fleet_dir, child),
+            target=_entry,
+            args=(wid, self.fleet_dir, child, self._platforms),
             name=f"fleet-{wid}", daemon=True)
         proc.start()
         child.close()

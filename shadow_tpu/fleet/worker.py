@@ -30,15 +30,16 @@ import signal
 import sys
 
 
-def worker_main(worker_id: str, fleet_dir: str, conn) -> int:
-    # Workers are independent JAX processes: CPU platform unless the
-    # fleet says otherwise, sharing the repo-local compile cache so
-    # job N's compile is job N+1's (and every sibling worker's) hit.
+def worker_main(worker_id: str, fleet_dir: str, conn,
+                platforms: str | None = None) -> int:
+    # Workers are independent JAX processes on the platforms the
+    # fleet process chose (runner.worker_platforms), sharing the
+    # compile cache so job N's compile is job N+1's (and every
+    # sibling worker's) hit.
     import jax
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        jax.config.update("jax_platforms", plat)
+    if platforms:
+        jax.config.update("jax_platforms", platforms)
     from shadow_tpu.utils.compcache import enable_compile_cache
 
     enable_compile_cache()
@@ -91,5 +92,6 @@ def worker_main(worker_id: str, fleet_dir: str, conn) -> int:
             return 0             # drained: one preempted result, out
 
 
-def _entry(worker_id: str, fleet_dir: str, conn):
-    sys.exit(worker_main(worker_id, fleet_dir, conn))
+def _entry(worker_id: str, fleet_dir: str, conn,
+           platforms: str | None = None):
+    sys.exit(worker_main(worker_id, fleet_dir, conn, platforms))

@@ -11,9 +11,11 @@ structures live in libshadow_native.so:
   (pool.py)
 - logsort: stable (time, seq) argsort for the log writer
 
-The library builds on demand with `make` (g++ is part of the
-toolchain); everything has a pure-Python fallback so the package
-works where a compiler is unavailable.
+Every process runs `make` before loading the library (a no-op when
+it is up to date), so the library loaded always matches src/ — never
+a stale build left beside the checkout. g++ is part of the
+toolchain; everything has a pure-Python fallback so the package works
+where the build fails.
 """
 
 from __future__ import annotations
@@ -45,7 +47,7 @@ def load() -> ctypes.CDLL | None:
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    if not _LIB_PATH.exists() and not _build():
+    if not _build():
         return None
     try:
         lib = ctypes.CDLL(str(_LIB_PATH))

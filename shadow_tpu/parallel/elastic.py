@@ -74,7 +74,7 @@ from flax import struct
 from jax import lax
 from jax.tree_util import tree_map_with_path
 
-from shadow_tpu.core import simtime
+from shadow_tpu.core import collectives, simtime
 
 I32 = jnp.int32
 I64 = jnp.int64
@@ -362,8 +362,8 @@ def make_sentinel_fn(axis: str | None = None):
             dmax = dmin = d
             offender = jnp.full((), -1, I32)
         else:
-            dmax = lax.pmax(d, axis)
-            dmin = lax.pmin(d, axis)
+            dmax = collectives.pmax(d, axis)
+            dmin = collectives.pmin(d, axis)
             n = lax.psum(jnp.ones((), I32), axis)
             n_max = lax.psum((d == dmax).astype(I32), axis)
             # suspects = the minority digest's holders (ties blame the
@@ -372,7 +372,7 @@ def make_sentinel_fn(axis: str | None = None):
             minority_is_max = n_max * 2 <= n
             suspect = jnp.where(minority_is_max, d == dmax, d != dmax)
             idx = lax.axis_index(axis).astype(I32)
-            offender = lax.pmin(jnp.where(suspect, idx, n), axis)
+            offender = collectives.pmin(jnp.where(suspect, idx, n), axis)
         mismatch = dmax != dmin
         first = mismatch & (st.trip == 0)
         trip = st.trip + mismatch.astype(I32)

@@ -29,21 +29,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.tree_util import tree_map_with_path
 
-try:                                  # jax >= 0.6 top-level name
-    from jax import shard_map as _shard_map
-except ImportError:                   # 0.4.x: experimental home, and the
-    # replication-check kwarg is still called check_rep there
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma)
-
-from shadow_tpu.core import simtime
+from shadow_tpu.core import collectives
 from shadow_tpu.core.engine import (
     EngineStats,
     resolve_sparse_lanes,
@@ -163,7 +153,7 @@ def route_outbox_sharded(
     order = jnp.argsort(tgt, stable=True)
     tgt_s = tgt[order]
     ok = tgt_s < num_shards
-    rank = segment_ranks(tgt_s)
+    rank = segment_ranks(tgt_s, num_shards)
 
     # Pack EVERY plane — the i64 time split into two i32 words — into
     # one buffer so the per-window exchange is exactly ONE collective
@@ -220,7 +210,7 @@ def route_outbox_sharded(
     # all-to-all + insert pipeline is elided (layer 3). The pmax'd
     # predicate is identical on every shard, so skipping the
     # collective is coherent (the narrow-tier precedent).
-    gmax = lax.pmax(jnp.max(jnp.where(ok, rank, -1)) + 1, axis)
+    gmax = collectives.pmax(jnp.max(jnp.where(ok, rank, -1)) + 1, axis)
     empty = gmax == 0
 
     def elide(qq):
@@ -269,10 +259,10 @@ def _replicate_scalars(sim, initial_sim, stats: EngineStats, axis: str):
     ob = sim.outbox
     # route_elided rides along: the elision branch is decided by a
     # pmax'd census, so the count is already identical on every shard.
-    narrow_pinned = (lax.pmax(ob.narrow_hit, axis),
-                     lax.pmax(ob.narrow_miss, axis),
-                     lax.pmax(ob.max_occupied, axis),
-                     lax.pmax(ob.route_elided, axis))
+    narrow_pinned = (collectives.pmax(ob.narrow_hit, axis),
+                     collectives.pmax(ob.narrow_miss, axis),
+                     collectives.pmax(ob.max_occupied, axis),
+                     collectives.pmax(ob.route_elided, axis))
     # The telemetry ring is pinned the same way: its scalars (count,
     # prev_*) and planes already hold globally-reduced values — the
     # delta-psum below would multiply them by the shard count.
@@ -334,11 +324,11 @@ def _replicate_scalars(sim, initial_sim, stats: EngineStats, axis: str):
     stats = EngineStats(
         events_processed=lax.psum(stats.events_processed, axis),
         micro_steps=lax.psum(stats.micro_steps, axis),
-        windows=lax.pmax(stats.windows, axis),
+        windows=collectives.pmax(stats.windows, axis),
         # the fastpath branch is globally decided (census_fn psum), so
         # every shard counted the same hits/misses — pin, don't sum
-        fastpath_hit=lax.pmax(stats.fastpath_hit, axis),
-        fastpath_miss=lax.pmax(stats.fastpath_miss, axis),
+        fastpath_hit=collectives.pmax(stats.fastpath_hit, axis),
+        fastpath_miss=collectives.pmax(stats.fastpath_miss, axis),
     )
     return sim, stats
 
@@ -402,7 +392,7 @@ def _make_whole_run(mesh: Mesh, axis: str, sim, step_fn, *,
             lane_id=lane,
             route_fn=_sharded_route_fn(axis, num_shards, lane,
                                        exchange_capacity, narrow),
-            min_fn=lambda x: lax.pmin(x, axis),
+            min_fn=lambda x: collectives.pmin(x, axis),
             bulk_fn=bulk_fn,
             # fault_fn closes over replicated plan constants and
             # derives everything from wend, which the pmin barrier
@@ -431,7 +421,7 @@ def _make_whole_run(mesh: Mesh, axis: str, sim, step_fn, *,
     # pvary annotations throughout; replication of the declared-P()
     # outputs is guaranteed by _replicate_scalars psumming every
     # scalar leaf (and verified by the bit-identity tests).
-    shmapped = _shard_map(
+    shmapped = shard_map(
         _body, mesh=mesh, in_specs=(specs,), out_specs=(specs, stats_specs),
         check_vma=False,
     )
@@ -511,7 +501,7 @@ def make_sharded_window(mesh: Mesh, axis: str, sim_template, cfg, step_fn,
             emit_capacity=cfg.emit_capacity, lane_id=lane,
             route_fn=_sharded_route_fn(axis, num_shards, lane,
                                        exchange_capacity, narrow),
-            min_fn=lambda x: lax.pmin(x, axis),
+            min_fn=lambda x: collectives.pmin(x, axis),
             bulk_fn=bulk_fn, fault_fn=fault_fn,
             telem_fn=make_telem_fn(axis), wstart=wstart,
             sparse_lanes=resolve_sparse_lanes(cfg),
@@ -522,7 +512,7 @@ def make_sharded_window(mesh: Mesh, axis: str, sim_template, cfg, step_fn,
         out_sim, stats = _replicate_scalars(out_sim, local_sim, stats, axis)
         return out_sim, stats, next_min
 
-    shmapped = _shard_map(
+    shmapped = shard_map(
         _body, mesh=mesh, in_specs=(specs, P(), P()),
         out_specs=(specs, stats_specs, P()), check_vma=False,
     )
@@ -564,7 +554,7 @@ def make_sharded_chunk(mesh: Mesh, axis: str, sim_template, cfg, step_fn,
             lane_fn=lambda s: s.net.lane_id,
             route_fn=_sharded_route_fn(axis, num_shards, lane,
                                        exchange_capacity, narrow),
-            min_fn=lambda x: lax.pmin(x, axis),
+            min_fn=lambda x: collectives.pmin(x, axis),
             bulk_fn=bulk_fn, fault_fn=fault_fn,
             telem_fn=make_telem_fn(axis),
             sparse_lanes=resolve_sparse_lanes(cfg),
@@ -576,7 +566,7 @@ def make_sharded_chunk(mesh: Mesh, axis: str, sim_template, cfg, step_fn,
         out_sim, stats = _replicate_scalars(out_sim, local_sim, stats, axis)
         return out_sim, stats, next_min
 
-    shmapped = _shard_map(
+    shmapped = shard_map(
         _body, mesh=mesh, in_specs=(specs, stats_specs, P()),
         out_specs=(specs, stats_specs, P()), check_vma=False,
     )

@@ -342,6 +342,16 @@ class SweepDriver:
     def _prewarm_round(self, k: int, specs) -> None:
         if self.prewarm is False or self.rounds[k]["prewarm"]:
             return
+        if self.prewarm is None:
+            from shadow_tpu.fleet.runner import worker_chips, \
+                worker_platforms
+
+            if worker_chips(worker_platforms()):
+                # compiling here would start this process's backend
+                # and take the chip before the worker that needs it
+                self.log(f"sweep: round {k} prewarm skipped: the "
+                         "worker holds the chip")
+                return
         fn = self.prewarm if callable(self.prewarm) \
             else (lambda s: _default_prewarm(s, self.log))
         infos = fn(specs)

@@ -70,7 +70,7 @@ import jax.numpy as jnp
 from flax import struct
 from jax import lax
 
-from shadow_tpu.core import simtime
+from shadow_tpu.core import collectives, simtime
 
 I32 = jnp.int32
 I64 = jnp.int64
@@ -210,10 +210,10 @@ def make_telem_fn(axis: str | None = None):
             return lax.psum(x, axis)
 
         def pmax(x):
-            return lax.pmax(x, axis)
+            return collectives.pmax(x, axis)
 
         def pmin(x):
-            return lax.pmin(x, axis)
+            return collectives.pmin(x, axis)
 
     def telem_fn(sim, wstart, wend, ev_delta, ms_delta,
                  active_lanes=None, fastpath=None, inject_deltas=None):

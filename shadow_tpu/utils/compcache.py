@@ -1,22 +1,26 @@
 """Shared persistent-compile-cache configuration.
 
-Every entry point (bench.py, tools/scale_run.py, the CLI, the test
-suite) must point JAX's persistent compilation cache at the SAME
-repo-local directory: the whole short-TPU-window strategy (see
-tools/tpu_watch.py) depends on one entry point's compile being every
-other entry point's cache hit. One helper, four callers — the three
-config knobs live nowhere else.
+Every entry point (bench.py, tools/scale_run.py, the CLI, the fleet
+worker, the test suite) points JAX's persistent compilation cache at
+the SAME directory, so one entry point's compile is every other entry
+point's cache hit. The directory is part of the cache's key, so it
+must not move between runs:
+
+- `JAX_COMPILATION_CACHE_DIR`, when set, is used exactly as given.
+  Whoever set it owns that directory; nothing here claims, redirects
+  or overrides it.
+- Otherwise the fixed repo-local `<checkout>/.jax_cache`.
 
 XLA:CPU cache entries embed the compile machine's CPU features (the
 AOT loader refuses — or worse, mis-executes wide-vector code paths —
 when the executing host lacks features the compiling host had). The
-cache directory is therefore CLAIMED by the first host that writes
-it: `enable_compile_cache` records the host's CPU-feature fingerprint
-in a sidecar (machine.json) and, when a later host's fingerprint
+repo-local directory is therefore CLAIMED by the first host that
+writes it: `cache_dir` records the host's CPU-feature fingerprint in
+a sidecar (machine.json) and, when a later host's fingerprint
 disagrees, logs a warning and redirects that host to a
 per-fingerprint subdirectory — a fresh compile namespace instead of
-loading foreign AOT entries. Same-featured hosts keep sharing the
-primary cache; SHADOW_NO_COMPILE_CACHE=1 opts out entirely.
+loading foreign AOT entries. SHADOW_NO_COMPILE_CACHE=1 opts out
+entirely.
 """
 
 from __future__ import annotations
@@ -80,12 +84,20 @@ def _claim_or_redirect(cache: pathlib.Path, fp: str,
     return redirect
 
 
+def cache_dir(log=None) -> pathlib.Path:
+    """The persistent-cache directory every entry point shares (the
+    AOT program store lives under it too, compile/store.py)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return pathlib.Path(env)
+    cache = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+    return _claim_or_redirect(cache, machine_fingerprint(), log)
+
+
 def enable_compile_cache(log=None) -> None:
     import jax
 
     if os.environ.get("SHADOW_NO_COMPILE_CACHE"):
         return
-    cache = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
-    cache = _claim_or_redirect(cache, machine_fingerprint(), log)
-    jax.config.update("jax_compilation_cache_dir", str(cache))
+    jax.config.update("jax_compilation_cache_dir", str(cache_dir(log)))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)

@@ -247,3 +247,17 @@ def test_cli_main_sharded_end_to_end(tmp_path):
         assert outs[0]["events"] == other["events"]
         assert outs[0]["windows"] == other["windows"]
         assert outs[0].get("app_rcvd") == other.get("app_rcvd")
+
+
+def test_cli_workers_above_device_count_fails(tmp_path, capsys):
+    """-w N with N above the device count is refused, and the message
+    names the count: one shard per device, never a silent clamp."""
+    from shadow_tpu.cli import main as cli_main
+
+    conf = tmp_path / "phold.xml"
+    conf.write_text(REFERENCE_PHOLD_XML)
+    rc = cli_main([str(conf), "-w", "9", "--platform", "cpu",
+                   "-d", str(tmp_path / "data")])
+    assert rc == 1
+    err = capsys.readouterr().err
+    assert "--workers 9" in err and "8 cpu device(s)" in err

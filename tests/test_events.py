@@ -219,3 +219,18 @@ def test_insert_flat_impls_bit_identical():
             err_msg=f"{f} diverged between impls")
     # overflow must have engaged (n >> free capacity) and be counted
     assert int(qa.overflow) > 0
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_segment_ranks_matches_loop(seed):
+    """segment_ranks counts per key; the loop is the reference."""
+    from shadow_tpu.core.events import segment_ranks
+
+    rng = np.random.default_rng(seed)
+    keys = np.sort(rng.integers(0, 9, size=200))   # keys in [0, 8]
+    want, run = [], 0
+    for i, k in enumerate(keys):
+        run = run + 1 if i and k == keys[i - 1] else 0
+        want.append(run)
+    got = np.asarray(segment_ranks(jnp.asarray(keys, jnp.int32), 8))
+    np.testing.assert_array_equal(got, want)

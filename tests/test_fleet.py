@@ -332,3 +332,34 @@ def test_fleet_status_readonly(tmp_path, capsys):
     assert out["jobs"]["ok-0"] == "done"
     # status never mutates the journal (a live fleet owns it)
     assert open(str(tmp_path / "journal.log"), "rb").read() == before
+
+
+# ------------------------------------------------------- chip ownership
+
+@pytest.mark.parametrize("workers,platforms,refused", [
+    (2, None, True),       # both would start on the host's TPU
+    (2, "tpu", True),
+    (1, None, False),      # one worker owns the chip
+    (2, "cpu", False),     # CPU workers hold no chip
+])
+def test_fleet_refuses_workers_that_fight_for_the_chip(
+        tmp_path, monkeypatch, workers, platforms, refused):
+    """On a host with a TPU the pool may hold one chip-holding worker
+    (a JAX process takes every chip), refused before anything is
+    spawned or written; the check reads the PCI bus, not a backend."""
+    from jax._src import hardware_utils
+
+    from shadow_tpu.fleet import runner
+
+    monkeypatch.setattr(hardware_utils,
+                        "num_available_tpu_chips_and_device_id",
+                        lambda: (1, None))
+    monkeypatch.setattr(runner, "worker_platforms", lambda: platforms)
+    fd = tmp_path / "fleet"
+    if refused:
+        with pytest.raises(ValueError, match="1 TPU chip"):
+            runner.FleetRunner(str(fd), _policy(), [], workers=workers)
+        assert not fd.exists()
+    else:
+        r = runner.FleetRunner(str(fd), _policy(), [], workers=workers)
+        assert r._platforms == platforms

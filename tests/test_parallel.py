@@ -133,3 +133,36 @@ def test_sharded_preserves_initial_scalar_counters():
     sim, stats = run_sharded(b, mesh, "hosts",
                              app_handlers=(pingpong.handler,))
     assert int(jax.device_get(sim.events.overflow)) == 3
+
+
+@pytest.mark.parametrize("ties", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.int64, jnp.uint64, jnp.int32])
+def test_collectives_min_max_exact_for_64_bit(dtype, ties):
+    """core.collectives.pmin/pmax: 64-bit operands reduce as two
+    32-bit words (the TPU compiler lowers only Sum for 64-bit
+    all-reduce); values that tie on the high word, straddle zero or
+    sit at the type's ends must come out exact."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from shadow_tpu.core import collectives
+
+    info = np.iinfo(dtype)
+    vals = [info.min, info.max, 0, 1, (1 << 31) + 5, (1 << 31) + 3,
+            (7 << 32) + 9, (7 << 32) + 2] if info.bits == 64 else [
+        info.min, info.max, 0, 1, -5, 17, 3, 3]
+    if ties:    # min and max each decided by the low word alone
+        vals = [((7 << 32) if info.bits == 64 else (7 << 24)) + k
+                for k in (9, 2, 5, 2, 9, 4)]
+    vals = [v for v in vals if info.min <= v <= info.max]
+    x = np.array((vals * 8)[:8], dtype=dtype)
+    mesh = Mesh(np.array(jax.devices()[:8]), ("s",))
+
+    def f(v):
+        return (collectives.pmin(v[0], "s")[None],
+                collectives.pmax(v[0], "s")[None])
+
+    lo, hi = jax.jit(shard_map(f, mesh=mesh, in_specs=P("s"),
+                               out_specs=P("s")))(jnp.asarray(x))
+    assert (np.asarray(lo) == x.min()).all() and lo.dtype == x.dtype
+    assert (np.asarray(hi) == x.max()).all() and hi.dtype == x.dtype

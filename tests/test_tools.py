@@ -524,3 +524,36 @@ def test_compcache_machine_claim_and_redirect(tmp_path):
     assert compcache._claim_or_redirect(cache, fp, msgs.append) == cache
     assert json.loads((cache / "machine.json").read_text())[
         "fingerprint"] == fp
+
+
+def test_compcache_honours_jax_compilation_cache_dir(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache directory as
+    given: no claim sidecar, no redirect, the AOT store under it, and
+    compiled entries written there."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from shadow_tpu.compile import store
+    from shadow_tpu.utils import compcache
+
+    d = tmp_path / "cc"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(d))
+    monkeypatch.delenv("SHADOW_AOT_DIR", raising=False)
+    monkeypatch.delenv("SHADOW_NO_COMPILE_CACHE", raising=False)
+    prev = (jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    try:
+        compcache.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == str(d)
+        assert store.default_root() == d / "aot"
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        compilation_cache.reset_cache()
+        jax.jit(lambda x: x * 3 + 1).lower(jnp.arange(7)).compile()
+        assert [p for p in d.iterdir() if p.is_file()]
+        assert not (d / "machine.json").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          prev[1])
+        compilation_cache.reset_cache()

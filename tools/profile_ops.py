@@ -7,7 +7,6 @@ from __future__ import annotations
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "tpu,cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
