@@ -87,12 +87,15 @@ class EngineStats:
     # the fast path is enabled; both stay 0 when it is off.
     fastpath_hit: jax.Array      # [] i64
     fastpath_miss: jax.Array     # [] i64
+    # Events the bulk window pass (bulk_fn) committed; the rest of
+    # events_processed ran through the serial fixpoint. 0 without one.
+    bulk_events: jax.Array       # [] i64
 
     @staticmethod
     def create() -> "EngineStats":
         z = jnp.zeros((), I64)
         return EngineStats(events_processed=z, micro_steps=z, windows=z,
-                           fastpath_hit=z, fastpath_miss=z)
+                           fastpath_hit=z, fastpath_miss=z, bulk_events=z)
 
     # Host-side accumulation across attempts/rebuilds. The supervisor
     # carries totals over an escalation boundary, where the pre-trip
@@ -106,6 +109,7 @@ class EngineStats:
             windows=self.windows + other.windows,
             fastpath_hit=self.fastpath_hit + other.fastpath_hit,
             fastpath_miss=self.fastpath_miss + other.fastpath_miss,
+            bulk_events=self.bulk_events + other.bulk_events,
         )
 
     def as_dict(self) -> dict:
@@ -115,6 +119,7 @@ class EngineStats:
             "windows": int(self.windows),
             "fastpath_hit": int(self.fastpath_hit),
             "fastpath_miss": int(self.fastpath_miss),
+            "bulk_events": int(self.bulk_events),
         }
 
     @staticmethod
@@ -125,7 +130,8 @@ class EngineStats:
                            micro_steps=v("micro_steps"),
                            windows=v("windows"),
                            fastpath_hit=v("fastpath_hit"),
-                           fastpath_miss=v("fastpath_miss"))
+                           fastpath_miss=v("fastpath_miss"),
+                           bulk_events=v("bulk_events"))
 
 
 # route_fn(sim) -> sim: deliver the outbox into destination queues.
@@ -277,136 +283,162 @@ def step_window(sim, stats: EngineStats, step_fn: StepFn, wend,
     if telem_fn is not None:
         ev0 = stats.events_processed
         ms0 = stats.micro_steps
-    # Open-system injection (inject/staging.py) merges FIRST, before
-    # the fault rewrite and the bulk/census passes: an injected event
-    # with timestamp inside this window must be census-visible and
-    # drain exactly like one an application scheduled. Trace-time
-    # no-op when Sim.inject is None (the default).
-    inject_deltas = None
-    if getattr(sim, "inject", None) is not None:
-        from shadow_tpu.inject.staging import merge_staged
-        sim, inj_w, drop_w, def_w = merge_staged(
-            sim, 0 if wstart is None else wstart, wend, lane_id)
-        inject_deltas = (inj_w, drop_w, def_w)
-    if fault_fn is not None:
-        sim = fault_fn(sim, wend)
-    # Specialization guard (compile/specialize.py): on a
-    # capability-trimmed program, evaluate one cheap predicate per
-    # dropped capability right after the fault rewrite (the only
-    # in-window writer of the watched tables) — a trip is latched
-    # sticky and becomes a fatal health fault at gather time.
-    # Trace-time no-op when Sim.guard is None (every full program).
-    if getattr(sim, "guard", None) is not None:
-        from shadow_tpu.compile.specialize import guard_update
-        sim = guard_update(sim, wend)
+    # Each layer runs under its own jax.named_scope (shadow_window,
+    # shadow_bulk, shadow_serial, shadow_route, shadow_barrier): the
+    # names ride every op's op_name into the compiled program and the
+    # profiler's trace, so device time splits by layer. Scopes are
+    # trace-time metadata only; the program's ops are unchanged.
+    with jax.named_scope("shadow_window"):
+        # Open-system injection (inject/staging.py) merges FIRST,
+        # before the fault rewrite and the bulk/census passes: an
+        # injected event with timestamp inside this window must be
+        # census-visible and drain exactly like one an application
+        # scheduled. Trace-time no-op when Sim.inject is None (the
+        # default).
+        inject_deltas = None
+        if getattr(sim, "inject", None) is not None:
+            from shadow_tpu.inject.staging import merge_staged
+            sim, inj_w, drop_w, def_w = merge_staged(
+                sim, 0 if wstart is None else wstart, wend, lane_id)
+            inject_deltas = (inj_w, drop_w, def_w)
+        if fault_fn is not None:
+            sim = fault_fn(sim, wend)
+        # Specialization guard (compile/specialize.py): on a
+        # capability-trimmed program, evaluate one cheap predicate per
+        # dropped capability right after the fault rewrite (the only
+        # in-window writer of the watched tables) — a trip is latched
+        # sticky and becomes a fatal health fault at gather time.
+        # Trace-time no-op when Sim.guard is None (every full program).
+        if getattr(sim, "guard", None) is not None:
+            from shadow_tpu.compile.specialize import guard_update
+            sim = guard_update(sim, wend)
     if bulk_fn is not None:
-        sim, n_bulk = bulk_fn(sim, wend)
-        stats = stats.replace(
-            events_processed=stats.events_processed + n_bulk)
+        with jax.named_scope("shadow_bulk"):
+            sim, n_bulk = bulk_fn(sim, wend)
+            stats = stats.replace(
+                events_processed=stats.events_processed + n_bulk,
+                bulk_events=stats.bulk_events + n_bulk)
 
     if adv_attr is not None and getattr(sim, "causality", None) is None:
         adv_attr = None
     S = int(sparse_lanes) if sparse_lanes else 0
-    n_active = None
-    if S > 0 or telem_fn is not None or adv_attr is not None:
-        active = sim.events.min_time() < jnp.asarray(wend, simtime.DTYPE)
-        n_active = jnp.sum(active, dtype=I32)  # shard-LOCAL lane count
-    fastpath = jnp.zeros((), jnp.bool_)
-    if S > 0:
-        n_global = (census_fn or _identity)(n_active)
-        # Require at least one live lane: an all-quiet window's
-        # full-width fixpoint terminates immediately, so compaction
-        # would pay gather+scatter for nothing (bulk-pass workloads
-        # consume whole windows before the fixpoint every round).
-        hit = (n_global > 0) & (n_global <= S)
+    with jax.named_scope("shadow_serial"):
+        n_active = None
+        if S > 0 or telem_fn is not None or adv_attr is not None:
+            active = sim.events.min_time() < jnp.asarray(wend,
+                                                         simtime.DTYPE)
+            n_active = jnp.sum(active, dtype=I32)  # shard-LOCAL lanes
+        fastpath = jnp.zeros((), jnp.bool_)
+        if S > 0:
+            n_global = (census_fn or _identity)(n_active)
+            # Require at least one live lane: an all-quiet window's
+            # full-width fixpoint terminates immediately, so compaction
+            # would pay gather+scatter for nothing (bulk-pass workloads
+            # consume whole windows before the fixpoint every round).
+            hit = (n_global > 0) & (n_global <= S)
 
-        def _full_body(op):
-            fsim, fstats = op
-            return window_fixpoint(
-                fsim, fstats, step_fn, wend, emit_capacity, lane_id)
-
-        if S < sim.events.num_hosts:
-            def _compact_body(op):
+            def _full_body(op):
                 fsim, fstats = op
-                idx = active_indices(active, S)
-                lane_c = (idx if lane_id is None
-                          else jnp.asarray(lane_id, I32)[idx])
-                csim = gather_lanes(fsim, idx)
-                csim, fstats = window_fixpoint(
-                    csim, fstats, step_fn, wend, emit_capacity, lane_c)
-                return scatter_lanes(fsim, csim, idx), fstats
+                return window_fixpoint(
+                    fsim, fstats, step_fn, wend, emit_capacity, lane_id)
 
-            sim, stats = jax.lax.cond(hit, _compact_body, _full_body,
-                                      (sim, stats))
+            if S < sim.events.num_hosts:
+                def _compact_body(op):
+                    fsim, fstats = op
+                    idx = active_indices(active, S)
+                    lane_c = (idx if lane_id is None
+                              else jnp.asarray(lane_id, I32)[idx])
+                    csim = gather_lanes(fsim, idx)
+                    csim, fstats = window_fixpoint(
+                        csim, fstats, step_fn, wend, emit_capacity, lane_c)
+                    return scatter_lanes(fsim, csim, idx), fstats
+
+                sim, stats = jax.lax.cond(hit, _compact_body, _full_body,
+                                          (sim, stats))
+            else:
+                # This (shard-local) width is already <= S: there is
+                # nothing to narrow, so run full width unconditionally
+                # — but keep the GLOBAL hit/miss accounting below, so
+                # the decision record is shard-count-invariant (a
+                # 64-host serial run compacts to S=16 while its 8-shard
+                # twin runs 8-wide shards as-is; both must count the
+                # same hits).
+                sim, stats = _full_body((sim, stats))
+            stats = stats.replace(
+                fastpath_hit=stats.fastpath_hit + hit.astype(I64),
+                fastpath_miss=stats.fastpath_miss + (~hit).astype(I64))
+            fastpath = hit
         else:
-            # This (shard-local) width is already <= S: there is
-            # nothing to narrow, so run full width unconditionally —
-            # but keep the GLOBAL hit/miss accounting below, so the
-            # decision record is shard-count-invariant (a 64-host
-            # serial run compacts to S=16 while its 8-shard twin runs
-            # 8-wide shards as-is; both must count the same hits).
-            sim, stats = _full_body((sim, stats))
-        stats = stats.replace(
-            fastpath_hit=stats.fastpath_hit + hit.astype(I64),
-            fastpath_miss=stats.fastpath_miss + (~hit).astype(I64))
-        fastpath = hit
-    else:
-        sim, stats = window_fixpoint(sim, stats, step_fn, wend,
-                                     emit_capacity, lane_id)
-    if telem_fn is not None:
-        # inject_deltas is passed only when injection is live, so
-        # hand-written telem_fns without the kwarg keep working
-        kw = ({"inject_deltas": inject_deltas}
-              if inject_deltas is not None else {})
-        sim = telem_fn(sim, wend if wstart is None else wstart, wend,
-                       stats.events_processed - ev0,
-                       stats.micro_steps - ms0,
-                       n_active, fastpath, **kw)
-    if flow_fn is not None:
-        # flow flight-recorder (telemetry/flows.py): samples the
-        # staged outbox, so it must also run BEFORE route_fn clears it
-        sim = flow_fn(sim, wend if wstart is None else wstart, wend)
-    if adv_attr is not None:
-        # window-advance attribution (telemetry/causality.py): the
-        # census reduction makes the latched active count GLOBAL, so
-        # the replicated [W] plane stays shard-identical
-        from shadow_tpu.telemetry.causality import advance_latch
-        cause, edge_a, edge_b, raw_jump = adv_attr
-        sim = advance_latch(
-            sim, wend if wstart is None else wstart, wend,
-            cause, edge_a, edge_b, raw_jump,
-            (census_fn or _identity)(n_active))
-    sim = route_fn(sim)
-    if getattr(sim, "lanes", None) is not None:
-        # lane barrier (core/lanes.py): reduce the per-host latch
-        # planes per lane, trip + freeze sick lanes, and — when the
-        # program is resident (Sim.admission, fleet/admission.py) —
-        # enforce lease horizons and keep FREE lanes empty, all at
-        # this barrier. After the route so this window's deliveries
-        # are attributed (and a delivery past a lease edge is flushed
-        # the window it arrives), before the min so frozen/expired
-        # lanes stop holding the global advance back.
-        from shadow_tpu.core.lanes import window_update
-        sim = window_update(sim, wend)
-    if sentinel_fn is not None:
-        # cross-shard integrity sentinel (parallel/elastic.py): digest
-        # the replicated leaves AFTER the route barrier restored the
-        # replication invariant (_replicate_scalars runs inside
-        # route_fn) and the lane barrier settled — any pmax-vs-pmin
-        # digest disagreement here is silent divergence, latched
-        # sticky. Trace-time no-op when Sim.sentinel is None.
-        sim = sentinel_fn(sim, wend)
-    stats = stats.replace(windows=stats.windows + 1)
-    local_min = jnp.min(sim.events.min_time())
-    if getattr(sim, "inject", None) is not None:
-        # staged-but-unmerged events join the advance rule: a quiet
-        # queue must still jump to the next injected timestamp
-        # instead of declaring the run over
-        from shadow_tpu.inject.staging import staged_pending_min
-        local_min = jnp.minimum(local_min,
-                                staged_pending_min(sim.inject))
-    next_min = min_fn(local_min)
+            sim, stats = window_fixpoint(sim, stats, step_fn, wend,
+                                         emit_capacity, lane_id)
+    with jax.named_scope("shadow_window"):
+        if telem_fn is not None:
+            # inject_deltas is passed only when injection is live, so
+            # hand-written telem_fns without the kwarg keep working
+            kw = ({"inject_deltas": inject_deltas}
+                  if inject_deltas is not None else {})
+            sim = telem_fn(sim, wend if wstart is None else wstart, wend,
+                           stats.events_processed - ev0,
+                           stats.micro_steps - ms0,
+                           n_active, fastpath, **kw)
+        if flow_fn is not None:
+            # flow flight-recorder (telemetry/flows.py): samples the
+            # staged outbox, so it must also run BEFORE route_fn
+            # clears it
+            sim = flow_fn(sim, wend if wstart is None else wstart, wend)
+        if adv_attr is not None:
+            # window-advance attribution (telemetry/causality.py): the
+            # census reduction makes the latched active count GLOBAL,
+            # so the replicated [W] plane stays shard-identical
+            from shadow_tpu.telemetry.causality import advance_latch
+            cause, edge_a, edge_b, raw_jump = adv_attr
+            sim = advance_latch(
+                sim, wend if wstart is None else wstart, wend,
+                cause, edge_a, edge_b, raw_jump,
+                (census_fn or _identity)(n_active))
+    with jax.named_scope("shadow_route"):
+        sim = route_fn(sim)
+    with jax.named_scope("shadow_window"):
+        if getattr(sim, "lanes", None) is not None:
+            # lane barrier (core/lanes.py): reduce the per-host latch
+            # planes per lane, trip + freeze sick lanes, and — when the
+            # program is resident (Sim.admission, fleet/admission.py) —
+            # enforce lease horizons and keep FREE lanes empty, all at
+            # this barrier. After the route so this window's
+            # deliveries are attributed (and a delivery past a lease
+            # edge is flushed the window it arrives), before the min
+            # so frozen/expired lanes stop holding the global advance
+            # back.
+            from shadow_tpu.core.lanes import window_update
+            sim = window_update(sim, wend)
+        if sentinel_fn is not None:
+            # cross-shard integrity sentinel (parallel/elastic.py):
+            # digest the replicated leaves AFTER the route barrier
+            # restored the replication invariant (_replicate_scalars
+            # runs inside route_fn) and the lane barrier settled — any
+            # pmax-vs-pmin digest disagreement here is silent
+            # divergence, latched sticky. Trace-time no-op when
+            # Sim.sentinel is None.
+            sim = sentinel_fn(sim, wend)
+        stats = stats.replace(windows=stats.windows + 1)
+        next_min = _next_min(sim, min_fn)
     return sim, stats, next_min
+
+
+def _next_min(sim, min_fn):
+    """The barrier: the earliest pending time over this shard's queue
+    heads (and staged-but-unmerged injections), reduced by min_fn to
+    the global value."""
+    with jax.named_scope("shadow_barrier"):
+        local_min = jnp.min(sim.events.min_time())
+        if getattr(sim, "inject", None) is not None:
+            # staged-but-unmerged events join the advance rule: a quiet
+            # queue must still jump to the next injected timestamp
+            # instead of declaring the run over
+            from shadow_tpu.inject.staging import staged_pending_min
+            local_min = jnp.minimum(local_min,
+                                    staged_pending_min(sim.inject))
+        return min_fn(local_min)
 
 
 def make_wend_fn(*, min_jump: int, end_time: int,
@@ -617,20 +649,21 @@ def make_chunk_body(step_fn: StepFn, *, end_time: int, wend_fn,
         def body(carry):
             i, sim, stats, ws = carry
             adv = None
-            if tracing:
-                from shadow_tpu.telemetry.causality import (
-                    CAUSE_INJECT_HORIZON,
-                )
-                wend, cause, edge_a, edge_b, raw = explain(sim, ws)
-                if streamed:
-                    cause = jnp.where(sim.inject.horizon < wend,
-                                      CAUSE_INJECT_HORIZON, cause)
-                    wend = jnp.minimum(wend, sim.inject.horizon)
-                adv = (cause, edge_a, edge_b, raw)
-            else:
-                wend = wend_fn(sim, ws)
-                if streamed:
-                    wend = jnp.minimum(wend, sim.inject.horizon)
+            with jax.named_scope("shadow_window"):
+                if tracing:
+                    from shadow_tpu.telemetry.causality import (
+                        CAUSE_INJECT_HORIZON,
+                    )
+                    wend, cause, edge_a, edge_b, raw = explain(sim, ws)
+                    if streamed:
+                        cause = jnp.where(sim.inject.horizon < wend,
+                                          CAUSE_INJECT_HORIZON, cause)
+                        wend = jnp.minimum(wend, sim.inject.horizon)
+                    adv = (cause, edge_a, edge_b, raw)
+                else:
+                    wend = wend_fn(sim, ws)
+                    if streamed:
+                        wend = jnp.minimum(wend, sim.inject.horizon)
             sim, stats, next_min = step_window(
                 sim, stats, step_fn, wend,
                 emit_capacity=emit_capacity, lane_id=lane,
@@ -703,34 +736,36 @@ def run(
     def body(carry):
         sim, stats, wstart = carry
         adv = None
-        if tracing:
-            # same attribution rule (and clamp-priority order) as the
-            # static make_wend_fn explain — the whole-run program's
-            # advance plane must be bit-identical to the chunked
-            # drivers' (telemetry/causality.py)
-            from shadow_tpu.telemetry.causality import (
-                CAUSE_END_TIME,
-                CAUSE_FAULT_RECORD,
-                CAUSE_MIN_JUMP,
-            )
-            wend = wstart + min_jump
-            cause = jnp.asarray(CAUSE_MIN_JUMP, I32)
-            if ft_c is not None:
-                nxt = jnp.min(jnp.where(ft_c > wstart, ft_c,
-                                        simtime.INVALID))
-                cause = jnp.where(nxt < wend, CAUSE_FAULT_RECORD, cause)
-                wend = jnp.minimum(wend, nxt)
-            cause = jnp.where(end_time + 1 < wend, CAUSE_END_TIME,
-                              cause)
-            wend = jnp.minimum(wend, end_time + 1)
-            neg1 = jnp.asarray(-1, I32)
-            adv = (cause, neg1, neg1, min_jump)
-        else:
-            wend = jnp.minimum(wstart + min_jump, end_time + 1)
-            if ft_c is not None:
-                nxt = jnp.min(jnp.where(ft_c > wstart, ft_c,
-                                        simtime.INVALID))
-                wend = jnp.minimum(wend, nxt)
+        with jax.named_scope("shadow_window"):
+            if tracing:
+                # same attribution rule (and clamp-priority order) as
+                # the static make_wend_fn explain — the whole-run
+                # program's advance plane must be bit-identical to the
+                # chunked drivers' (telemetry/causality.py)
+                from shadow_tpu.telemetry.causality import (
+                    CAUSE_END_TIME,
+                    CAUSE_FAULT_RECORD,
+                    CAUSE_MIN_JUMP,
+                )
+                wend = wstart + min_jump
+                cause = jnp.asarray(CAUSE_MIN_JUMP, I32)
+                if ft_c is not None:
+                    nxt = jnp.min(jnp.where(ft_c > wstart, ft_c,
+                                            simtime.INVALID))
+                    cause = jnp.where(nxt < wend, CAUSE_FAULT_RECORD,
+                                      cause)
+                    wend = jnp.minimum(wend, nxt)
+                cause = jnp.where(end_time + 1 < wend, CAUSE_END_TIME,
+                                  cause)
+                wend = jnp.minimum(wend, end_time + 1)
+                neg1 = jnp.asarray(-1, I32)
+                adv = (cause, neg1, neg1, min_jump)
+            else:
+                wend = jnp.minimum(wstart + min_jump, end_time + 1)
+                if ft_c is not None:
+                    nxt = jnp.min(jnp.where(ft_c > wstart, ft_c,
+                                            simtime.INVALID))
+                    wend = jnp.minimum(wend, nxt)
         sim, stats, next_min = step_window(
             sim, stats, step_fn, wend, emit_capacity, lane_id,
             route_fn, min_fn, bulk_fn, fault_fn, telem_fn, wstart,
@@ -738,18 +773,12 @@ def run(
         )
         return sim, stats, next_min
 
-    local_min = jnp.min(sim.events.min_time())
-    if getattr(sim, "inject", None) is not None:
-        # Whole-run programs never return to the host, so the feeder
-        # must have staged the ENTIRE trace (Feeder.fill_all; horizon
-        # stays INVALID). The staged minimum joins the first-window
-        # rule so a trace-only run (empty queue) still starts.
-        from shadow_tpu.inject.staging import staged_pending_min
-        local_min = jnp.minimum(local_min,
-                                staged_pending_min(sim.inject))
-    first = jnp.maximum(
-        min_fn(local_min),
-        jnp.asarray(start_time, simtime.DTYPE),
-    )
+    # Whole-run programs never return to the host, so an injection
+    # feeder must have staged the ENTIRE trace (Feeder.fill_all;
+    # horizon stays INVALID). The staged minimum joins the first-window
+    # rule (_next_min) so a trace-only run (empty queue) still starts.
+    with jax.named_scope("shadow_window"):
+        first = jnp.maximum(_next_min(sim, min_fn),
+                            jnp.asarray(start_time, simtime.DTYPE))
     sim, stats, _ = jax.lax.while_loop(cond, body, (sim, stats, first))
     return sim, stats

@@ -495,16 +495,19 @@ def _insert_sorted_scatter(q: EventQueue, rowc, packed, n, H, K):
     # (v5e, jax 0.9: 23 operands took 7.6 s at n=4,096 and 152 s at
     # n=16,384, where 2 operands took 5.4 s; the 10,240-host PHOLD
     # route has n=491,520). Same stable order, so the same stream.
-    row_o, perm = jax.lax.sort((rowc, jnp.arange(n, dtype=I32)),
-                               num_keys=1, is_stable=True)
-    packed_o = packed[perm]                                # [n, P]
-    valid_o = row_o < H
+    with jax.named_scope("sort"):
+        row_o, perm = jax.lax.sort((rowc, jnp.arange(n, dtype=I32)),
+                                   num_keys=1, is_stable=True)
+    with jax.named_scope("permute"):
+        packed_o = packed[perm]                            # [n, P]
+        valid_o = row_o < H
 
     # per-destination-row arrival counts (invalid entries fall in the
     # dropped bin H) and each row's start offset in the sorted stream
-    cnt = jnp.zeros((H + 1,), I32).at[row_o].add(
-        1, indices_are_sorted=True)[:H]
-    start = jnp.cumsum(cnt, dtype=I32) - cnt               # [H] excl
+    with jax.named_scope("count"):
+        cnt = jnp.zeros((H + 1,), I32).at[row_o].add(
+            1, indices_are_sorted=True)[:H]
+        start = jnp.cumsum(cnt, dtype=I32) - cnt           # [H] excl
 
     free = ~q.valid()                                      # [H, K]
     nfree = jnp.sum(free, axis=1, dtype=I32)
@@ -519,38 +522,42 @@ def _insert_sorted_scatter(q: EventQueue, rowc, packed, n, H, K):
              if q.overflow_h is not None else None)
 
     def _select_sweep(_):
-        # each row's arrivals as a contiguous window of the stream
-        pad_o = jnp.pad(packed_o, ((0, Wn), (0, 0)))
         use_pallas = False
         if jax.default_backend() == "tpu":
             from shadow_tpu.core import insert_pallas
 
             use_pallas = insert_pallas.mailbox_available(H)
-        if use_pallas:
-            # pipelined per-row HBM->VMEM DMAs instead of XLA's
-            # strictly serial H-iteration gather loop. Mosaic needs
-            # the DMA'd minor dim 128-aligned, so the stream is
-            # padded P -> 128 (the extra bytes ride otherwise-idle
-            # DMA bandwidth; the serial loop they replace was latency
-            # bound, not bandwidth bound).
-            wide = jnp.pad(pad_o, ((0, 0), (0, 128 - P)))
-            win = insert_pallas.mailbox_gather(wide, start, Wn)[..., :P]
-        else:
-            dnums = jax.lax.GatherDimensionNumbers(
-                offset_dims=(1, 2), collapsed_slice_dims=(),
-                start_index_map=(0,))
-            win = jax.lax.gather(
-                pad_o, start[:, None], dnums, slice_sizes=(Wn, P),
-                indices_are_sorted=True,
-                mode=jax.lax.GatherScatterMode.CLIP)       # [H, Wn, P]
-        f_rank = jnp.cumsum(free, axis=1, dtype=I32) - free
-        acc = packed_q
-        for j in range(Wn):
-            take = free & (f_rank == j) & (j < cnt)[:, None]
-            acc = jnp.where(take[:, :, None], win[:, j, None, :], acc)
-        ofl = jnp.sum(jnp.maximum(cnt - nfree, 0), dtype=I32)
+        with jax.named_scope("mailbox"):
+            # each row's arrivals as a contiguous window of the stream
+            pad_o = jnp.pad(packed_o, ((0, Wn), (0, 0)))
+            if use_pallas:
+                # pipelined per-row HBM->VMEM DMAs instead of XLA's
+                # strictly serial H-iteration gather loop. Mosaic needs
+                # the DMA'd minor dim 128-aligned, so the stream is
+                # padded P -> 128 (the extra bytes ride otherwise-idle
+                # DMA bandwidth; the serial loop they replace was
+                # latency bound, not bandwidth bound).
+                wide = jnp.pad(pad_o, ((0, 0), (0, 128 - P)))
+                win = insert_pallas.mailbox_gather(wide, start,
+                                                   Wn)[..., :P]
+            else:
+                dnums = jax.lax.GatherDimensionNumbers(
+                    offset_dims=(1, 2), collapsed_slice_dims=(),
+                    start_index_map=(0,))
+                win = jax.lax.gather(
+                    pad_o, start[:, None], dnums, slice_sizes=(Wn, P),
+                    indices_are_sorted=True,
+                    mode=jax.lax.GatherScatterMode.CLIP)   # [H, Wn, P]
+        with jax.named_scope("sweep"):
+            f_rank = jnp.cumsum(free, axis=1, dtype=I32) - free
+            acc = packed_q
+            for j in range(Wn):
+                take = free & (f_rank == j) & (j < cnt)[:, None]
+                acc = jnp.where(take[:, :, None], win[:, j, None, :], acc)
+            ofl = jnp.sum(jnp.maximum(cnt - nfree, 0), dtype=I32)
         return acc, ofl
 
+    @jax.named_scope("scatter")
     def _sorted_scatter(_):
         rank_o = segment_ranks(row_o, H)
         slot_map = _free_slot_of_rank(q, "sort")           # [H, K]
@@ -637,37 +644,43 @@ def insert_flat(
         return _insert_sorted_scatter(q, rowc, packed, n, H, K)
 
     if impl == "count":
-        G = INSERT_GROUP
-        pad = (-n) % G
-        rowp = jnp.pad(rowc, (0, pad), constant_values=H)
-        ng = rowp.shape[0] // G
-        gidx = jnp.arange(ng * G) // G
-        cnt = jnp.zeros((ng, H), I32).at[gidx, rowp].add(1, mode="drop")
-        base_excl = jnp.cumsum(cnt, axis=0, dtype=I32) - cnt
-        base = base_excl[
-            jnp.clip(gidx, 0, ng - 1), jnp.clip(rowp, 0, H - 1)]
-        rg = rowp.reshape(ng, G)
-        earlier = jnp.arange(G)[:, None] < jnp.arange(G)[None, :]
-        intra = jnp.sum(
-            (rg[:, :, None] == rg[:, None, :]) & earlier[None],
-            axis=1, dtype=I32).reshape(-1)
-        rank = (base + intra)[:n]
+        with jax.named_scope("count"):
+            G = INSERT_GROUP
+            pad = (-n) % G
+            rowp = jnp.pad(rowc, (0, pad), constant_values=H)
+            ng = rowp.shape[0] // G
+            gidx = jnp.arange(ng * G) // G
+            cnt = jnp.zeros((ng, H), I32).at[gidx, rowp].add(
+                1, mode="drop")
+            base_excl = jnp.cumsum(cnt, axis=0, dtype=I32) - cnt
+            base = base_excl[
+                jnp.clip(gidx, 0, ng - 1), jnp.clip(rowp, 0, H - 1)]
+            rg = rowp.reshape(ng, G)
+            earlier = jnp.arange(G)[:, None] < jnp.arange(G)[None, :]
+            intra = jnp.sum(
+                (rg[:, :, None] == rg[:, None, :]) & earlier[None],
+                axis=1, dtype=I32).reshape(-1)
+            rank = (base + intra)[:n]
         row_o, rank_o, packed_o, valid_o = rowc, rank, packed, valid
     else:
-        order = jnp.argsort(rowc, stable=True)
-        row_o = rowc[order]
-        packed_o = packed[order]
-        valid_o = row_o < H
-        rank_o = segment_ranks(row_o, H)
+        with jax.named_scope("sort"):
+            order = jnp.argsort(rowc, stable=True)
+        with jax.named_scope("permute"):
+            row_o = rowc[order]
+            packed_o = packed[order]
+            valid_o = row_o < H
+        with jax.named_scope("count"):
+            rank_o = segment_ranks(row_o, H)
 
-    slot_map = _free_slot_of_rank(q, impl)                 # [H,K]
-    cand = slot_map[
-        jnp.clip(row_o, 0, H - 1), jnp.clip(rank_o, 0, K - 1)]
-    fits = valid_o & (rank_o < K) & (cand < K)
-    r = jnp.where(fits, row_o, H)                          # OOB -> drop
-    s = jnp.where(fits, cand, K)
-
-    packed_q = _queue_packed(q).at[r, s].set(packed_o, mode="drop")
+    with jax.named_scope("count"):
+        slot_map = _free_slot_of_rank(q, impl)             # [H,K]
+        cand = slot_map[
+            jnp.clip(row_o, 0, H - 1), jnp.clip(rank_o, 0, K - 1)]
+    with jax.named_scope("scatter"):
+        fits = valid_o & (rank_o < K) & (cand < K)
+        r = jnp.where(fits, row_o, H)                      # OOB -> drop
+        s = jnp.where(fits, cand, K)
+        packed_q = _queue_packed(q).at[r, s].set(packed_o, mode="drop")
     ofl_h = None
     if q.overflow_h is not None:
         # destination-row attribution: non-fitting valid entries

@@ -51,6 +51,7 @@ from typing import Optional
 import numpy as np
 
 from shadow_tpu.core import simtime
+from shadow_tpu.core.engine import EngineStats
 from shadow_tpu.faults import escalate as escalate_mod
 from shadow_tpu.faults import health as health_mod
 from shadow_tpu.parallel import elastic as elastic_mod
@@ -183,14 +184,14 @@ class SupervisorResult:
         return rep
 
 
+_STAT_KEYS = tuple(f.name for f in dataclasses.fields(EngineStats))
+
+
 def _stats_get(wstats) -> dict:
     """Per-round EngineStats as host ints (one device_get)."""
     import jax
 
-    s = jax.device_get(wstats)
-    return {k: int(getattr(s, k)) for k in
-            ("events_processed", "micro_steps", "windows",
-             "fastpath_hit", "fastpath_miss")}
+    return jax.device_get(wstats).as_dict()
 
 
 def run_supervised(bundle, app_handlers=(), *, fault_fn=None,
@@ -330,8 +331,7 @@ def run_supervised(bundle, app_handlers=(), *, fault_fn=None,
 
     def _ckpt_extra(acc: dict) -> dict:
         stats = {k: base_stats.get(k, 0) + acc.get(k, 0)
-                 for k in ("events_processed", "micro_steps", "windows",
-                           "fastpath_hit", "fastpath_miss")}
+                 for k in _STAT_KEYS}
         return {"stats": stats, "run_id": run_id,
                 "escalations": [e.as_dict() for e in escalations]}
 
@@ -633,8 +633,6 @@ def run_supervised(bundle, app_handlers=(), *, fault_fn=None,
                 lane_incidents=tuple(lane_incidents),
                 compile_info=(dict(cinfo) if cinfo else None),
                 elastic=_elastic_block(), **kw)
-
-        from shadow_tpu.core.engine import EngineStats
 
         try:
             sim, stats, _ = ckpt.run_windows(

@@ -25,8 +25,6 @@ reference gets from its 4-key event sort (ref: event.c:110-153).
 
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
@@ -177,9 +175,9 @@ def route_outbox_sharded(
         sb_i32 = jnp.zeros((num_shards, C, 6 + W), I32).at[..., 0].set(-1)
         sb_i32 = sb_i32.at[row, slot].set(flat, mode="drop")
 
-        a2a = partial(lax.all_to_all, axis_name=axis, split_axis=0,
-                      concat_axis=0)
-        rb_i32 = a2a(sb_i32)
+        with jax.named_scope("shadow_exchange"):
+            rb_i32 = lax.all_to_all(sb_i32, axis, split_axis=0,
+                                    concat_axis=0)
 
         nn = num_shards * C
         ri32 = rb_i32.reshape(nn, 6 + W)
@@ -210,7 +208,9 @@ def route_outbox_sharded(
     # all-to-all + insert pipeline is elided (layer 3). The pmax'd
     # predicate is identical on every shard, so skipping the
     # collective is coherent (the narrow-tier precedent).
-    gmax = collectives.pmax(jnp.max(jnp.where(ok, rank, -1)) + 1, axis)
+    with jax.named_scope("shadow_exchange"):
+        gmax = collectives.pmax(jnp.max(jnp.where(ok, rank, -1)) + 1,
+                                axis)
     empty = gmax == 0
 
     def elide(qq):
@@ -329,6 +329,7 @@ def _replicate_scalars(sim, initial_sim, stats: EngineStats, axis: str):
         # every shard counted the same hits/misses — pin, don't sum
         fastpath_hit=collectives.pmax(stats.fastpath_hit, axis),
         fastpath_miss=collectives.pmax(stats.fastpath_miss, axis),
+        bulk_events=lax.psum(stats.bulk_events, axis),
     )
     return sim, stats
 
@@ -344,7 +345,7 @@ def _harness_specs(mesh: Mesh, axis: str, sim):
     specs = sim_specs(sim, axis)
     stats_specs = EngineStats(
         events_processed=P(), micro_steps=P(), windows=P(),
-        fastpath_hit=P(), fastpath_miss=P(),
+        fastpath_hit=P(), fastpath_miss=P(), bulk_events=P(),
     )
     return num_shards, specs, stats_specs
 

@@ -2167,6 +2167,7 @@ class ProcessRuntime:
                 windows=total.windows + 1,
                 fastpath_hit=total.fastpath_hit + stats.fastpath_hit,
                 fastpath_miss=total.fastpath_miss + stats.fastpath_miss,
+                bulk_events=total.bulk_events + stats.bulk_events,
             )
             now = int(wend)
         # collect payload-pool entries whose packets died on device

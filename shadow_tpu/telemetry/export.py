@@ -238,6 +238,9 @@ def final_counters(sim, stats=None) -> dict:
         out["windows"] = int(stats.windows)
         out["fastpath_hit"] = int(stats.fastpath_hit)
         out["fastpath_miss"] = int(stats.fastpath_miss)
+        # events the bulk window pass committed; the serial fixpoint
+        # committed the rest of events_processed
+        out["bulk_events"] = int(stats.bulk_events)
     return out
 
 

@@ -844,14 +844,7 @@ def run_windows(bundle, app_handlers=(), *, end_time: int | None = None,
             # harmless and discarded.
             nxt = chunk_fn(csim, EngineStats.create(), cnext)
             nm = int(cnext)
-            total = total.replace(
-                events_processed=(total.events_processed
-                                  + cstats.events_processed),
-                micro_steps=total.micro_steps + cstats.micro_steps,
-                windows=total.windows + cstats.windows,
-                fastpath_hit=total.fastpath_hit + cstats.fastpath_hit,
-                fastpath_miss=total.fastpath_miss + cstats.fastpath_miss,
-            )
+            total = total.add(cstats)
             wend_c = min(nm, end + 1)
             if (next_ckpt is not None and checkpoint_path is not None
                     and nm >= next_ckpt and nm <= end):
@@ -896,6 +889,7 @@ def run_windows(bundle, app_handlers=(), *, end_time: int | None = None,
             windows=total.windows + 1,
             fastpath_hit=total.fastpath_hit + stats.fastpath_hit,
             fastpath_miss=total.fastpath_miss + stats.fastpath_miss,
+            bulk_events=total.bulk_events + stats.bulk_events,
         )
         nm = int(next_min)
         if feeder is not None:

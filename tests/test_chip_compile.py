@@ -14,6 +14,8 @@ test worker imports this file.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -87,6 +89,30 @@ def test_sort2_insert_takes_the_mailbox_on_tpu(one_chip, monkeypatch):
     words = _spec((n, W), jnp.int32, one_chip)
     compiled = jax.jit(events.insert_flat).lower(q, *flat, words).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_route_scopes_keep_the_mailbox_kernel_name(one_chip, monkeypatch):
+    """The route's step scopes (core/events.py) reach the compiled TPU
+    program's op names, and the Pallas kernel keeps its instruction
+    name, which the benchmark's mailbox_roofline finds it by."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    H, K = 1_024, 48
+    q, out = (jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                           jax.eval_shape(lambda: make(H, K)))
+              for make in (events.EventQueue.create, events.Outbox.create))
+
+    def route(q, out):
+        with jax.named_scope("shadow_route"):
+            return events.route_outbox(q, out)
+
+    text = jax.jit(route).lower(q, out).compile().as_text()
+    kernel = [ln for ln in text.splitlines()
+              if re.match(r"\s*%?mailbox_gather[.\d]* = ", ln)]
+    assert kernel and all("/mailbox/" in ln for ln in kernel)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for step in ("sort", "permute", "count", "sweep", "mailbox"):
+        assert any("shadow_route/" in n and f"/{step}/" in n
+                   for n in names), step
 
 
 @pytest.mark.parametrize("op", ["pmin", "pmax"])

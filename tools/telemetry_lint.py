@@ -1744,6 +1744,15 @@ def lint_manifest_obj(man) -> tuple[list, list]:
         errors.append(
             f"fastpath_hit+miss = {fp[0]}+{fp[1]} exceeds the "
             f"{cw} windows the engine ran")
+    # bulk-pass commits are a part of the events the engine committed
+    be, ep = ctr.get("bulk_events"), ctr.get("events_processed")
+    if be is not None and (not isinstance(be, int)
+                           or isinstance(be, bool) or be < 0):
+        errors.append(f"counters.bulk_events must be a non-negative "
+                      f"integer, got {be!r}")
+    elif be is not None and isinstance(ep, int) and be > ep:
+        errors.append(f"bulk_events = {be} exceeds the {ep} events "
+                      f"the engine committed")
     # dual-mode conformance block (optional): counts must be coherent
     # non-negative ints summing to the per-workload verdicts, and a
     # divergence is always SURFACED as a warning
