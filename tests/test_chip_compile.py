@@ -91,6 +91,40 @@ def test_sort2_insert_takes_the_mailbox_on_tpu(one_chip, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_sort2_count_step_is_a_dot_at_the_narrow_shape(one_chip,
+                                                       monkeypatch):
+    """The insert of the PHOLD cells' narrow route tier (10,240 hosts x
+    ROUTE_NARROW = 24 outbox columns): its per-row arrival counts are
+    an MXU product, with no scatter left in the count step."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    H, K, W = 10_240, 48, events.NWORDS
+    n = H * events.ROUTE_NARROW
+    assert n < events.MXU_COUNT_LIMIT
+    q = jax.tree.map(lambda x: _spec(x.shape, x.dtype, one_chip),
+                     jax.eval_shape(lambda: events.EventQueue.create(H, K)))
+    flat = [_spec((n,), dt, one_chip) for dt in
+            (jnp.bool_, jnp.int32, jnp.int64, jnp.int32, jnp.int32,
+             jnp.int32)]
+    words = _spec((n, W), jnp.int32, one_chip)
+    text = jax.jit(events.insert_flat, static_argnames="impl").lower(
+        q, *flat, words, impl="sort2").compile().as_text()
+    count_ops = [ln for ln in text.splitlines()
+                 if re.search(r'op_name="[^"]*/count/', ln)]
+    assert not any(re.search(r"\bscatter\(", ln) for ln in count_ops)
+    assert any(re.search(r"\b(dot|convolution)\(", ln) for ln in count_ops)
+
+
+def test_key_counts_materialises_no_one_hot(one_chip):
+    """The one-hot operands stay inside the dot's fusion: the compiled
+    histogram at the narrow shape needs far less scratch than one
+    [n, 128] bf16 one-hot."""
+    H, n = 10_240, 10_240 * events.ROUTE_NARROW
+    keys = _spec((n,), jnp.int32, one_chip)
+    compiled = jax.jit(events.key_counts, static_argnums=1).lower(
+        keys, H).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < n * 128 * 2 // 8
+
+
 def test_route_scopes_keep_the_mailbox_kernel_name(one_chip, monkeypatch):
     """The route's step scopes (core/events.py) reach the compiled TPU
     program's op names, and the Pallas kernel keeps its instruction

@@ -11,12 +11,16 @@ compare raw queue planes pairwise. Shapes are chosen to exercise:
 - the select sweep (all destination rows under INSERT_SWEEP) and the
   sorted-scatter branch (a hot row overloaded past it),
 - queue-row overflow accounting (more arrivals than free slots),
+- sort2's per-row arrival counts (key_counts) as the MXU histogram
+  and as the scatter-add it keeps for the largest inputs,
 - SPARSE outbox rows: the UDP bulk pass stages replies at time-order
   columns (net/bulk.py ord_col), so occupied entries can sit past the
   per-row count with holes below them — the narrow gate must widen on
   the true occupied width, not the count (r4 review finding: gating
   on count silently dropped such entries).
 """
+
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -76,18 +80,57 @@ def _snap(q):
 
 
 def _assert_all_equal(q, out, narrows):
+    """Every impl at every narrow width, and sort2 once more with its
+    per-row counts forced onto the scatter-add that key_counts keeps
+    above MXU_COUNT_LIMIT (these shapes take the MXU product)."""
     ref = None
-    for impl in IMPLS:
+    runs = [(impl, ev.MXU_COUNT_LIMIT) for impl in IMPLS] + [("sort2", 0)]
+    for impl, limit in runs:
         for narrow in narrows:
-            q2, out2 = ev.route_outbox(q, out, impl=impl, narrow=narrow)
+            with mock.patch.object(ev, "MXU_COUNT_LIMIT", limit):
+                q2, out2 = ev.route_outbox(q, out, impl=impl, narrow=narrow)
             s = _snap(q2)
             if ref is None:
                 ref = s
             else:
                 for i, (a, b) in enumerate(zip(ref, s)):
-                    assert np.array_equal(a, b), (impl, narrow, i)
+                    assert np.array_equal(a, b), (impl, limit, narrow, i)
             assert int(jnp.sum(out2.count)) == 0  # cleared
     return ref
+
+
+def _counts_of(keys, H):
+    return jax.jit(ev.key_counts, static_argnums=1)(jnp.asarray(keys), H)
+
+
+@pytest.mark.parametrize("rows", ["uniform", "invalid", "one_row"])
+@pytest.mark.parametrize("H", [1, 7, 127, 128, 129, 1_000])
+def test_key_counts_match_bincount(H, rows):
+    """The MXU histogram equals np.bincount with the dropped key H left
+    out, whether or not H is a multiple of the 128-lane split; n = 1,001
+    fills no whole block of 128."""
+    rng = np.random.default_rng(H)
+    n = 1_001
+    keys = {"uniform": rng.integers(0, H + 1, n),
+            "invalid": np.full(n, H),
+            "one_row": np.full(n, H - 1)}[rows].astype(np.int32)
+    assert "dot_general" in str(jax.make_jaxpr(
+        lambda k: ev.key_counts(k, H))(keys))
+    got = np.asarray(_counts_of(keys, H))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, np.bincount(keys, minlength=H + 1)[:H])
+
+
+def test_key_counts_scatter_add_from_the_limit():
+    """At MXU_COUNT_LIMIT entries f32 sums stop being exact, and the
+    counts come from the sorted scatter-add instead."""
+    H, n = 1_000, ev.MXU_COUNT_LIMIT
+    keys = (jnp.arange(n, dtype=jnp.int64) * (H + 1) // n).astype(jnp.int32)
+    jaxpr = str(jax.make_jaxpr(lambda k: ev.key_counts(k, H))(keys))
+    assert "scatter-add" in jaxpr and "dot_general" not in jaxpr
+    np.testing.assert_array_equal(
+        np.asarray(_counts_of(keys, H)),
+        np.bincount(np.asarray(keys), minlength=H + 1)[:H])
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -100,6 +143,21 @@ def test_packed_rows_all_impls_identical(seed):
                     cols_of_row=lambda h: range(cnt[h]),
                     dst_of=lambda h, c: int(rng.integers(0, H)))
     _assert_all_equal(q, out, narrows=(0, 4, 8))
+
+
+@pytest.mark.parametrize("H", [127, 128, 129])
+def test_lane_boundary_rows_all_impls_identical(H):
+    """Host counts around the MXU count's 128-lane split, with most
+    arrivals on the last rows, next to the dropped bin H (few enough
+    per row for the select sweep)."""
+    rng = np.random.default_rng(H)
+    K, M, W = 8, 6, 6
+    q = _mkqueue(rng, H, K, W, fill=0.3)
+    cnt = rng.integers(0, 3, H)
+    out = _mkoutbox(rng, H, M, W,
+                    cols_of_row=lambda h: range(cnt[h]),
+                    dst_of=lambda h, c: H - 1 - int(rng.integers(0, 32)))
+    _assert_all_equal(q, out, narrows=(0, 3))
 
 
 def test_hot_row_overload_takes_scatter_branch_and_overflows():
